@@ -76,7 +76,6 @@ from p2p_distributed_tswap_tpu.parallel.mesh import (  # noqa: E402
     AGENTS_AXIS,
     TILES_AXIS,
     agent_tile_mesh,
-    shard_map,
 )
 from p2p_distributed_tswap_tpu.solver import invariants, mapd  # noqa: E402
 
@@ -122,11 +121,11 @@ def main():
     sweep_dev_mb = (args.replan_chunk * (args.side // args.tiles)
                     * args.side * 4) / 2**20
 
-    step_shard = shard_map(
+    step_shard = jax.shard_map(
         functools.partial(sharded2d.sharded2d_mapd_step, cfg),
         mesh=mesh, in_specs=(specs, P(), P(TILES_AXIS, None)),
         out_specs=specs, check_vma=False)
-    prime = jax.jit(shard_map(
+    prime = jax.jit(jax.shard_map(
         functools.partial(sharded2d._prime_2d, cfg),
         mesh=mesh, in_specs=(specs, P(TILES_AXIS, None)), out_specs=specs,
         check_vma=False))

@@ -240,7 +240,7 @@ def bench_fleet(args) -> dict:
            "--agents", str(args.fleet_agents), "--side", "24",
            "--tick-ms", "250", "--settle", "14",
            "--window", str(args.fleet_window), "--seed", "1",
-           "--solver", "tpu", "--world-toggle-cells", "6",
+           "--solver", "tpu", "--solverd-cpu", "--world-toggle-cells", "6",
            "--world-toggle-every", "5", "--no-trace",
            "--log-dir", "/tmp/jg_field_bench_fleet_logs",
            "--out", str(out)]

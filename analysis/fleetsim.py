@@ -68,7 +68,7 @@ from p2p_distributed_tswap_tpu.runtime import ha as _ha  # noqa: E402
 from p2p_distributed_tswap_tpu.runtime import region as regionlib  # noqa: E402,E501
 from p2p_distributed_tswap_tpu.runtime.bus_client import BusClient  # noqa: E402,E501
 from p2p_distributed_tswap_tpu.runtime.fleet import (  # noqa: E402
-    BUILD_DIR, ensure_built, wait_for_log)
+    BUILD_DIR, ensure_built, one_solverd_per_chip, wait_for_log)
 from p2p_distributed_tswap_tpu.runtime.simagent import SimAgentPool  # noqa: E402,E501
 
 
@@ -340,12 +340,14 @@ def run_rung(args, agents: int, tick_ms: int, spec) -> dict:
         _trace.configure(proc="simfleet")
         _events.configure("simfleet")
         if args.solver == "tpu":
+            one_solverd_per_chip(fed_total, args.solverd_cpu)
             for rid in range(fed_total):
                 tag = f"_r{rid}" if fed_total > 1 else ""
                 sd_cmd = [sys.executable, "-m",
                           "p2p_distributed_tswap_tpu.runtime.solverd",
                           "--port", str(home_port), "--map",
-                          args.map_file, "--warm", str(agents), "--cpu",
+                          args.map_file, "--warm", str(agents),
+                          *(["--cpu"] if args.solverd_cpu else []),
                           *regionlib.fed_cli_args(rid, fed_cols,
                                                   fed_rows, "solverd")]
                 sd_proc = spawn(f"solverd{tag}", sd_cmd)
@@ -735,7 +737,7 @@ def _final_digests(joiner) -> dict:
 def run_replay(capture: dict, log_dir, solver=None, shards=None,
                no_trace: bool = False, chaos=None, drain_s=None,
                label: str = "replay", regions=None,
-               ha: bool = False) -> dict:
+               ha: bool = False, solverd_cpu: bool = False) -> dict:
     """Re-drive a captured window open-loop as a DETERMINISTIC load
     (ISSUE 11): a fresh fleet (seeded from the capture), the captured
     tasks injected via the manager's ``taskat`` command at their
@@ -767,6 +769,8 @@ def run_replay(capture: dict, log_dir, solver=None, shards=None,
     heartbeat_s = float(fleet.get("heartbeat_s") or 2.0)
     fed_cols, fed_rows = regionlib.fed_parse_spec(regions)
     fed_total = fed_cols * fed_rows
+    if solver == "tpu":
+        one_solverd_per_chip(fed_total, solverd_cpu)
 
     ensure_built()
     map_file = f"/tmp/fleetsim_replay_{side}.map.txt"
@@ -829,7 +833,8 @@ def run_replay(capture: dict, log_dir, solver=None, shards=None,
             cmd = [sys.executable, "-m",
                    "p2p_distributed_tswap_tpu.runtime.solverd",
                    "--port", str(home_port), "--map", map_file,
-                   "--warm", str(agents), "--cpu",
+                   "--warm", str(agents),
+                   *(["--cpu"] if solverd_cpu else []),
                    *regionlib.fed_cli_args(rid, fed_cols, fed_rows,
                                            "solverd")]
             p = spawn(name, cmd)
@@ -1183,7 +1188,8 @@ def run_tenant_smoke(args) -> int:
         sd = spawn("solverd",
                    [sys.executable, "-m",
                     "p2p_distributed_tswap_tpu.runtime.solverd",
-                    "--port", str(port), "--map", args.map_file, "--cpu",
+                    "--port", str(port), "--map", args.map_file,
+                    *(["--cpu"] if args.solverd_cpu else []),
                     "--multi-tenant",
                     "--max-tenants", str(args.tenants)])
         if not wait_for_log(log_dir / "solverd.log", "solverd up", 600,
@@ -1391,6 +1397,10 @@ def main(argv=None) -> int:
     ap.add_argument("--heartbeat-s", type=float, default=2.0)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--solver", choices=["native", "tpu"], default="native")
+    ap.add_argument("--solverd-cpu", action="store_true",
+                    help="run solverd on the JAX CPU backend (--cpu): "
+                         "CPU smokes and tests; without it solverd runs "
+                         "on the accelerator or fails")
     ap.add_argument("--spec", default=None,
                     help="SLO spec JSON file (default: obs/slo.py "
                          "rated-load spec)")
@@ -1463,7 +1473,8 @@ def main(argv=None) -> int:
                          shards=args.replay_shards,
                          no_trace=args.no_trace,
                          drain_s=args.replay_drain_s,
-                         regions=args.replay_regions)
+                         regions=args.replay_regions,
+                         solverd_cpu=args.solverd_cpu)
         print(json.dumps({k: res[k] for k in
                           ("expected", "completed", "missing",
                            "extra_done", "done_dups", "mgr_completed",

@@ -199,7 +199,7 @@ def bench_fleet(args) -> dict:
            "--side", str(args.fleet_side),
            "--tick-ms", "250", "--settle", "16",
            "--window", str(args.fleet_window), "--seed", "1",
-           "--solver", "tpu", "--world-toggle-cells", "5",
+           "--solver", "tpu", "--solverd-cpu", "--world-toggle-cells", "5",
            "--world-toggle-every", "5", "--no-trace",
            "--log-dir", "/tmp/jg_sector_bench_fleet_logs",
            "--out", str(out)]

@@ -2604,6 +2604,9 @@ int main(int argc, char** argv) {
             if (!failed_over) {
               failed_over = true;
               trace_count("manager.solver_failovers");
+              // always-on too: chip_smoke.py reads it off the beacon to
+              // prove a "tpu" fleet never planned natively
+              metrics_count("manager.solver_failovers");
               trace_instant("manager.solver_failover");
               log_warn("⚠️  solver daemon silent for %lld ms; planning "
                        "natively until it responds\n",

@@ -57,8 +57,7 @@ register concatenations of the adjacent aligned tiles.
 Eligibility (``fused_eligible``): TPU backend, H % 8 == 0,
 W % 128 == 0, and the VMEM working set (distance scratch + mask + codes
 + doubling temporaries) fits — fields up to ~1024x1024.  Larger grids
-(4096^2) keep the strip kernel.  Kill-switch shared with the strip
-kernel: MAPD_NO_PALLAS=1.
+(4096^2) keep the strip kernel.
 
 Bit-identity: the integer math is the same recurrence as
 ops.distance._sweep_xla + directions_from_distance; verified in
@@ -127,8 +126,8 @@ def fused_eligible(h: int, w: int) -> bool:
     35 -> 66 ms/step, flagship 127 -> 156; round 3); the multi-field
     variant is the roofline's GO-signal layout but has no on-chip
     measurement yet (no TPU in this environment — see
-    results/field_engine_r11.json), so neither defaults on.  Kill switch
-    shared with the strip kernel: MAPD_NO_PALLAS=1 (via _on_tpu)."""
+    results/field_engine_r11.json), so neither defaults on.  The
+    multi-field kernel also aborts the v5e compiler (ROADMAP A2)."""
     mode = fused_mode()
     if not mode or not _on_tpu():
         return False

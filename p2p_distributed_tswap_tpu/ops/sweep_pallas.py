@@ -27,13 +27,11 @@ the doubling scan.
 Eligibility (``sweep_eligible``): TPU backend, H and W multiples of 128
 (covers the 256/512/1024/4096 benchmark grids; the reference's 100x100
 falls back to the XLA path, which is already sub-millisecond there).
-Kill-switch: MAPD_NO_PALLAS=1.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -41,11 +39,6 @@ import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# pallas API compat: the params class is ``CompilerParams`` on current
-# jax and ``TPUCompilerParams`` on the 0.4.x line — same fields
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 INF = np.int32(1 << 30)
 LANES = 128
@@ -56,8 +49,6 @@ INTERPRET = False
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    if os.environ.get("MAPD_NO_PALLAS") == "1":
-        return False
     try:
         if jax.default_backend() != "tpu":
             return False
@@ -265,7 +256,7 @@ def _sweep8_rows(d: jnp.ndarray, blocked: jnp.ndarray,
                                lambda ri, ci, hi: (ri, hmap(hi), ci, 0, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((segs, LANES), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=INTERPRET,
     )(d5, m4)

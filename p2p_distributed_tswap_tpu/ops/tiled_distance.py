@@ -34,7 +34,6 @@ from p2p_distributed_tswap_tpu.ops.distance import (
     _sweep,
     directions_from_distance,
 )
-from p2p_distributed_tswap_tpu.parallel.mesh import axis_size
 
 TILES_AXIS = "tiles"
 
@@ -44,7 +43,7 @@ def _exchange_boundary_rows(d: jnp.ndarray, axis_name: str):
     above and the first row of the band below, INF on the edge bands (no
     neighbor; ppermute leaves non-receiving shards with zeros, which must
     not look like distance 0)."""
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     perm_down = [(i, i + 1) for i in range(n_dev - 1)]  # send towards +H
     perm_up = [(i + 1, i) for i in range(n_dev - 1)]
     above = jax.lax.ppermute(d[:, -1:, :], axis_name, perm_down)
@@ -59,7 +58,7 @@ def _halo_relax(d: jnp.ndarray, free_local: jnp.ndarray,
                 axis_name: str) -> jnp.ndarray:
     """Relax each band's boundary rows against the neighbors' adjacent rows:
     ``d[:, 0] <- min(d[:, 0], above_neighbor_last_row + 1)`` and vice versa."""
-    if axis_size(axis_name) == 1:
+    if jax.lax.axis_size(axis_name) == 1:
         return d
     above, below = _exchange_boundary_rows(d, axis_name)
     d = d.at[:, :1, :].min(jnp.minimum(above + 1, INF))
@@ -149,7 +148,7 @@ def tiled_directions_from_distance(d: jnp.ndarray, free_local: jnp.ndarray,
     (the tail of :func:`tiled_direction_fields`, split out so callers
     needing BOTH the distances and the codes — e.g. the mesh solverd's
     dynamic-world sweep, parallel/solver_mesh.py — pay the sweep once)."""
-    if axis_size(axis_name) == 1:
+    if jax.lax.axis_size(axis_name) == 1:
         return directions_from_distance(d, free_local)
     above, below = _exchange_boundary_rows(d, axis_name)
     padded = jnp.concatenate([above, d, below], axis=1)  # (G, H_local+2, W)

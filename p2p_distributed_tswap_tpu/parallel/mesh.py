@@ -18,36 +18,6 @@ AGENTS_AXIS = "agents"
 TILES_AXIS = "tiles"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: the stable ``jax.shard_map``
-    (``check_vma``) when this jax has it, else the long-standing
-    ``jax.experimental.shard_map`` (same semantics; the replication
-    check there is spelled ``check_rep``).  Every mesh entry point in
-    this repo — the offline sharded solvers, the tiled sweeps, and the
-    mesh solverd serving path — routes through here, so a jax upgrade
-    or downgrade never strands the whole sharding stack again (this
-    container's jax 0.4.x is exactly that case)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(axis_name) -> int:
-    """Mesh-axis size inside shard_map, version-portable: the stable
-    ``jax.lax.axis_size`` when present, else ``lax.psum(1, axis)`` —
-    which constant-folds to a concrete Python int on every jax that
-    lacks the named accessor (verified on this container's 0.4.x)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _default_devices(n_devices: int | None = None):
     default = jax.config.jax_default_device
     devices = (jax.devices(default.platform) if default is not None

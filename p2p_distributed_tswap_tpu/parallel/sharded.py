@@ -44,8 +44,7 @@ from p2p_distributed_tswap_tpu.ops.distance import (
     pack_directions,
     packed_cells,
 )
-from p2p_distributed_tswap_tpu.parallel.mesh import (AGENTS_AXIS,
-    agent_mesh, shard_map)
+from p2p_distributed_tswap_tpu.parallel.mesh import AGENTS_AXIS, agent_mesh
 from p2p_distributed_tswap_tpu.solver import mapd as mapd_mod
 from p2p_distributed_tswap_tpu.solver.mapd import MapdState, init_state
 
@@ -184,7 +183,7 @@ def make_sharded_runner(cfg: SolverConfig, mesh: Mesh | None = None,
     state_specs = agent_state_specs()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(state_specs, P(), P()), out_specs=state_specs,
         check_vma=False)
     def run_shard(s, tasks, free):

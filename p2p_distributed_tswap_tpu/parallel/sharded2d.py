@@ -54,7 +54,6 @@ from p2p_distributed_tswap_tpu.parallel.mesh import (
     AGENTS_AXIS,
     TILES_AXIS,
     agent_tile_mesh,
-    shard_map,
 )
 from p2p_distributed_tswap_tpu.solver import mapd as mapd_mod
 from p2p_distributed_tswap_tpu.solver.mapd import MapdState, init_state
@@ -94,12 +93,13 @@ def _prime_2d(cfg: SolverConfig, s: MapdState, free_local: jnp.ndarray
     ceil(rows_local / r) is identical on every device — rows_local is
     uniform — so the tiled sweep's collective schedule lines up with no
     pmax needed."""
-    n = cfg.num_agents
     dirs_local = s.dirs
     rows_local, words_local = dirs_local.shape
     a_shard = jax.lax.axis_index(AGENTS_AXIS)
-    inv = jnp.zeros(n, jnp.int32).at[s.slot].set(
-        jnp.arange(n, dtype=jnp.int32))
+    # the inverse slot permutation as an argsort: the scatter that
+    # _next_hops_2d uses, fed init_state's iota slots here, aborts the
+    # v5e compiler (scatter_emitter.cc: operand_indices.size() == 1)
+    inv = jnp.argsort(s.slot).astype(jnp.int32)
     r = min(cfg.replan_chunk, rows_local)
     nchunks = -(-rows_local // r)
     lane = jnp.arange(r, dtype=jnp.int32)
@@ -213,7 +213,7 @@ def make_sharded2d_runner(cfg: SolverConfig, mesh: Mesh):
     specs = state_specs_2d()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, P(), P(TILES_AXIS, None)), out_specs=specs,
         check_vma=False)
     def run_shard(s, tasks, free_local):

@@ -60,8 +60,7 @@ from p2p_distributed_tswap_tpu.ops.tiled_distance import (
     tiled_directions_from_distance,
     tiled_distance_fields,
 )
-from p2p_distributed_tswap_tpu.parallel.mesh import (AGENTS_AXIS,
-    TILES_AXIS, shard_map)
+from p2p_distributed_tswap_tpu.parallel.mesh import AGENTS_AXIS, TILES_AXIS
 from p2p_distributed_tswap_tpu.solver.step import step_with_next_hops
 
 _SPEC_RE = re.compile(r"^(\d+)(?:x(\d+))?$")
@@ -225,7 +224,7 @@ class SolverMesh:
         @functools.partial(jax.jit, static_argnums=0)
         def mesh_step(cfg, pos, goal, slot, dirs, active):
             @functools.partial(
-                shard_map, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(P(), P(), P(), P(AGENTS_AXIS, None), P()),
                 out_specs=(P(), P(), P()), check_vma=False)
             def inner(pos, goal, slot, dirs_local, active):
@@ -247,7 +246,7 @@ class SolverMesh:
         @jax.jit
         def mesh_vstep(pos, goal, slot, active, dirs):
             @functools.partial(
-                shard_map, mesh=mesh,
+                jax.shard_map, mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P(AGENTS_AXIS, None)),
                 out_specs=(P(), P(), P()), check_vma=False)
             def inner(pos, goal, slot, active, dirs_local):
@@ -283,7 +282,7 @@ class SolverMesh:
             @jax.jit
             def fields_sharded(free, goals):
                 @functools.partial(
-                    shard_map, mesh=mesh,
+                    jax.shard_map, mesh=mesh,
                     in_specs=(P(), P(AGENTS_AXIS)),
                     out_specs=P(AGENTS_AXIS, None), check_vma=False)
                 def inner(free, goals_local):
@@ -296,7 +295,7 @@ class SolverMesh:
             @jax.jit
             def fields_sharded(free, goals):
                 @functools.partial(
-                    shard_map, mesh=mesh,
+                    jax.shard_map, mesh=mesh,
                     in_specs=(P(TILES_AXIS, None), P(AGENTS_AXIS)),
                     out_specs=P(AGENTS_AXIS, TILES_AXIS, None),
                     check_vma=False)
@@ -332,7 +331,7 @@ class SolverMesh:
             @jax.jit
             def fd_sharded(free, goals):
                 @functools.partial(
-                    shard_map, mesh=mesh,
+                    jax.shard_map, mesh=mesh,
                     in_specs=(P(), P(AGENTS_AXIS)),
                     out_specs=(P(AGENTS_AXIS, None),
                                P(AGENTS_AXIS, None, None),
@@ -349,7 +348,7 @@ class SolverMesh:
             @jax.jit
             def fd_sharded(free, goals):
                 @functools.partial(
-                    shard_map, mesh=mesh,
+                    jax.shard_map, mesh=mesh,
                     in_specs=(P(TILES_AXIS, None), P(AGENTS_AXIS)),
                     out_specs=(P(AGENTS_AXIS, TILES_AXIS, None),
                                P(AGENTS_AXIS, TILES_AXIS, None)),

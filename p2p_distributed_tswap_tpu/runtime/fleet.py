@@ -33,14 +33,34 @@ BUILD_DIR = REPO_ROOT / "cpp" / "build"
 
 
 def ensure_built() -> Path:
-    """Build the C++ runtime if needed; returns the build dir."""
-    if not (BUILD_DIR / "mapd_bus").exists():
-        subprocess.run(["cmake", "-S", str(REPO_ROOT / "cpp"), "-B",
-                        str(BUILD_DIR), "-G", "Ninja"], check=True,
-                       capture_output=True)
+    """Build the C++ runtime from the committed sources; returns the build
+    dir.  cmake configures once, and ninja runs every time, so binaries
+    left in a copied ``cpp/build/`` never run stale.  A file lock keeps
+    concurrent callers (xdist workers) from building at once."""
+    import fcntl
+
+    BUILD_DIR.mkdir(parents=True, exist_ok=True)
+    with open(BUILD_DIR / ".build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not (BUILD_DIR / "CMakeCache.txt").exists():
+            subprocess.run(["cmake", "-S", str(REPO_ROOT / "cpp"), "-B",
+                            str(BUILD_DIR), "-G", "Ninja"], check=True,
+                           capture_output=True)
         subprocess.run(["ninja", "-C", str(BUILD_DIR)], check=True,
                        capture_output=True)
     return BUILD_DIR
+
+
+def one_solverd_per_chip(regions: int, solverd_cpu: bool) -> None:
+    """Refuse a federated --solver tpu fleet that would start one solverd
+    per region on the chip: only one process can hold it.  Raised before
+    anything is spawned; a CPU-backend solverd (--cpu) may be replicated."""
+    if regions > 1 and not solverd_cpu:
+        raise RuntimeError(
+            f"{regions} regions would start {regions} solverd processes "
+            "on one chip, and only one process can hold it; serve them "
+            "from one multi-tenant solverd (--multi-tenant / --tenants), "
+            "or run solverd on the CPU (--cpu) for a smoke")
 
 
 def wait_for_log(path, needle: str, timeout_s: float,
@@ -110,6 +130,9 @@ class Fleet:
         # keeps today's single-pair fleet byte-identical.
         fed_cols, fed_rows = regionlib.fed_parse_spec(regions)
         fed_total = fed_cols * fed_rows
+        if mode == "centralized" and solver == "tpu":
+            one_solverd_per_chip(fed_total,
+                                 "--cpu" in (solverd_args or []))
         build = ensure_built()
         self.procs: List[subprocess.Popen] = []
         self._names: List[str] = []
@@ -194,14 +217,15 @@ class Fleet:
                                  *fed_args, *(solverd_args or [])])
                 # wait for the readiness banner (printed AFTER any
                 # --warm pre-compile) so the manager never opens with a
-                # failover window; a startup death just means the
-                # manager plans natively; without logs fall back to a
-                # fixed headroom sleep
-                if self.log_dir:
-                    wait_for_log(self.log_dir / f"solverd{tag}.log",
-                                 "solverd up", 240, proc=sd_proc)
-                else:
-                    time.sleep(8)  # accelerator init headroom
+                # failover window; a daemon that never comes up fails
+                # the fleet instead of leaving the manager to plan
+                # natively under a "tpu" label
+                if not wait_for_log(self.log_dir / f"solverd{tag}.log",
+                                    "solverd up", 240, proc=sd_proc):
+                    self.close()
+                    raise RuntimeError(
+                        f"solverd{tag} never came up; see "
+                        f"{self.log_dir / f'solverd{tag}.log'}")
         # control-plane HA (ISSUE 15): ha=True (or JG_HA=1 in the
         # fleet env) pairs every region's manager with a warm standby
         # that tails its ledger1 replication stream and takes over on

@@ -667,7 +667,8 @@ def run_matrix(capture: dict, faults, log_dir, no_trace: bool,
             capture, log_dir, solver=solver, shards=shards,
             no_trace=no_trace, drain_s=drain_s,
             chaos=None if kind == "clean" else fault,
-            label=f"{i}_{kind}", regions=regions, ha=ha)
+            label=f"{i}_{kind}", regions=regions, ha=ha,
+            solverd_cpu=True)  # a CPU fault-injection gate
         verdict = classify(kind, res)
         verdict["fault_detail"] = fault.summary()
         verdict["elapsed_s"] = round(time.monotonic() - t0, 1)

@@ -160,7 +160,7 @@ then
     # must never pass the gate for a build whose run crashed early
     rm -f /tmp/jg_dynworld_ci.json
     JAX_PLATFORMS=cpu python analysis/fleetsim.py \
-        --agents 12 --side 24 --tick-ms 250 --solver tpu \
+        --agents 12 --side 24 --tick-ms 250 --solver tpu --solverd-cpu \
         --settle 12 --window 15 --seed 1 --no-trace \
         --world-toggle-cells 5 --world-toggle-every 5 \
         --spec scripts/fleetsim_ci.spec.json \
@@ -183,7 +183,7 @@ PY
     # ISSUE 9 satellite (ROADMAP item 2 headroom): N tenants admitted
     # LIVE through solver.admit tenant_hello — exit 0 iff every tenant
     # is welcomed and completes >= 1 task
-    JAX_PLATFORMS=cpu python analysis/fleetsim.py --tenants 2 \
+    JAX_PLATFORMS=cpu python analysis/fleetsim.py --tenants 2 --solverd-cpu \
         --agents 4 --side 24 --settle 10 --window 25 \
         --log-dir /tmp/jg_dynworld_ci_logs
 else

