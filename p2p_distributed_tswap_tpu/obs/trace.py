@@ -16,6 +16,9 @@ Design constraints, in priority order:
    read.  Nothing in the jitted device programs is touched either way: all
    spans live on the HOST side of the dispatch boundary, where a
    ``perf_counter_ns`` pair per phase is noise against a ~100 ms tick.
+   In a process that has imported JAX, an enabled span also opens a
+   ``jax.profiler.TraceAnnotation`` of its name, so under a device
+   profiler it lands on the xplane's host plane, on the device ops' clock.
 2. **Mergeable across processes.**  Event timestamps are wall-clock-anchored
    microseconds: each tracer records ``(time_ns, perf_counter_ns)`` once at
    creation and emits ``anchor + (mono - mono0)``.  Durations stay purely
@@ -50,6 +53,7 @@ from __future__ import annotations
 import collections
 import json
 import os
+import sys
 import threading
 import time
 from typing import Iterator, Optional
@@ -86,22 +90,31 @@ _NULL_SPAN = _NullSpan()
 class _LiveSpan:
     """One open span; appends a Chrome complete ("X") event on exit."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_parent")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = None
 
     def __enter__(self):
         stack = self._tracer._stack()
         self._parent = stack[-1] if stack else None
         stack.append(self.name)
+        # only a process that has imported JAX can be under its profiler
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur_ns = time.perf_counter_ns() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         stack = self._tracer._stack()
         if stack and stack[-1] == self.name:
             stack.pop()

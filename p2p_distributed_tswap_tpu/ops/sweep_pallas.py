@@ -1,6 +1,6 @@
 """Pallas TPU kernel for the fast-sweeping directional relax.
 
-The round-3 flagship step profile (analysis/step_profile.py, SCALING.md)
+The round-3 flagship step profile (an ablation on the chip, SCALING.md)
 put the replan sweeps at ~88% of step time, so this is THE kernel worth
 hand-writing (VERDICT r2 item 6).  The XLA path implements each
 directional sweep as a Hillis-Steele doubling scan — log2(axis) rounds of
@@ -117,6 +117,7 @@ def _sweep_rows(d: jnp.ndarray, blocked: jnp.ndarray,
         out_specs=pl.BlockSpec((None, h, LANES), lambda ri, si: (ri, 0, si),
                                memory_space=pltpu.VMEM),
         interpret=INTERPRET,
+        name="sweep_rows",
     )(d, blocked)
 
 
@@ -259,5 +260,6 @@ def _sweep8_rows(d: jnp.ndarray, blocked: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=INTERPRET,
+        name="sweep8_rows",
     )(d5, m4)
     return out.reshape(r, h, w)

@@ -41,6 +41,15 @@ from p2p_distributed_tswap_tpu.solver.step import (
 
 _FAR = jnp.int32(1 << 20)  # > any grid manhattan distance
 
+# The step's phases as ``jax.named_scope`` names: each op's HLO ``op_name``
+# carries the scopes it was traced under (``jit(mapd_step)/replan/while/
+# body/sweep/...``), so a device trace can sum op time per phase.  ``sweep``
+# and ``pack`` nest inside ``replan``; ``rules`` and ``move`` are opened in
+# solver/step.py; ``record`` is the step's bookkeeping (the path record, the
+# timestep and, in a solve, the done test); ``prime`` is the t=0 burst.
+PHASES = ("assign", "replan", "sweep", "pack", "rules", "move", "record",
+          "prime")
+
 
 @struct.dataclass
 class MapdState:
@@ -224,9 +233,11 @@ def _replan(cfg: SolverConfig, s: MapdState, free: jnp.ndarray) -> MapdState:
         sel = -jax.lax.top_k(-priority, r)[0]       # r lowest flagged ids
         valid = sel < n
         selc = jnp.clip(sel, 0, n - 1)
-        fields = direction_fields(free, s.goal[selc],
-                                  max_rounds=cfg.max_sweep_rounds)
-        fields = pack_directions(fields.reshape(r, cfg.num_cells))
+        with jax.named_scope("sweep"):
+            fields = direction_fields(free, s.goal[selc],
+                                      max_rounds=cfg.max_sweep_rounds)
+        with jax.named_scope("pack"):
+            fields = pack_directions(fields.reshape(r, cfg.num_cells))
         # Invalid lanes clip to agent n-1, whose (goal, slot) pair is still
         # consistent — so their writes are redundant but *correct*, and no
         # out-of-bounds scatter index is ever needed (XLA CPU has been seen
@@ -262,10 +273,11 @@ def prime_fields(cfg: SolverConfig, s: MapdState, free: jnp.ndarray) -> MapdStat
             pack_directions(fields.reshape(r, cfg.num_cells)))
         return dirs, None
 
-    dirs, _ = jax.lax.scan(chunk, s.dirs,
-                           jnp.arange(nchunks, dtype=jnp.int32))
-    return s.replace(dirs=dirs,
-                     need_replan=jnp.zeros(n, bool))
+    with jax.named_scope("prime"):
+        dirs, _ = jax.lax.scan(chunk, s.dirs,
+                               jnp.arange(nchunks, dtype=jnp.int32))
+        return s.replace(dirs=dirs,
+                         need_replan=jnp.zeros(n, bool))
 
 
 def _record(cfg: SolverConfig, s: MapdState) -> MapdState:
@@ -333,33 +345,44 @@ def mapd_step(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray,
     """
     stale = cfg.stale_mode
     if stale:
-        s = _commit_pending(cfg, s)
-    s = _transitions(cfg, s, tasks)
-    any_idle = jnp.any((s.phase == AgentPhase.IDLE) & ~jnp.all(s.task_used))
-    s = jax.lax.cond(any_idle, lambda s: _assign(cfg, s, tasks), lambda s: s, s)
-    s = (replan_fn or _replan)(cfg, s, free)
+        with jax.named_scope("rules"):
+            s = _commit_pending(cfg, s)
+    with jax.named_scope("assign"):
+        s = _transitions(cfg, s, tasks)
+        any_idle = jnp.any((s.phase == AgentPhase.IDLE)
+                           & ~jnp.all(s.task_used))
+        s = jax.lax.cond(any_idle, lambda s: _assign(cfg, s, tasks),
+                         lambda s: s, s)
+    with jax.named_scope("replan"):
+        s = (replan_fn or _replan)(cfg, s, free)
     if stale:
-        s = _broadcast_view(cfg, s)
-        if nh_factory is None:
-            nh_fn = lambda sl, po: step_mod.next_hops(cfg, s.dirs, sl, po)
-        else:
-            nh_fn = nh_factory(cfg, s.dirs)
-        visible = (jnp.ones(cfg.num_agents, bool)
-                   if cfg.view_ttl_steps is None
-                   else (s.t - s.vstamp) <= cfg.view_ttl_steps)
+        # step_stale opens "rules" for its decisions and "move" for the
+        # cascade itself
+        with jax.named_scope("rules"):
+            s = _broadcast_view(cfg, s)
+            if nh_factory is None:
+                nh_fn = lambda sl, po: step_mod.next_hops(cfg, s.dirs, sl, po)
+            else:
+                nh_fn = nh_factory(cfg, s.dirs)
+            visible = (jnp.ones(cfg.num_agents, bool)
+                       if cfg.view_ttl_steps is None
+                       else (s.t - s.vstamp) <= cfg.view_ttl_steps)
         pos, pend_from, pend_push = step_mod.step_stale(
             cfg, s.pos, s.goal, s.slot, nh_fn, s.vpos, s.vgoal, visible,
             jnp.ones(cfg.num_agents, bool))
         s = s.replace(pos=pos, pend_from=pend_from, pend_push=pend_push)
         if cfg.swap_commit_delay == 0:
-            s = _commit_pending(cfg, s)
-        return _record(cfg, s)
+            with jax.named_scope("rules"):
+                s = _commit_pending(cfg, s)
+        with jax.named_scope("record"):
+            return _record(cfg, s)
     if nh_factory is None:
         pos, goal, slot = step_parallel(cfg, s.pos, s.goal, s.slot, s.dirs)
     else:
         pos, goal, slot = step_with_next_hops(
             cfg, s.pos, s.goal, s.slot, nh_factory(cfg, s.dirs))
-    return _record(cfg, s.replace(pos=pos, goal=goal, slot=slot))
+    with jax.named_scope("record"):
+        return _record(cfg, s.replace(pos=pos, goal=goal, slot=slot))
 
 
 def _finished(cfg: SolverConfig, s: MapdState) -> jnp.ndarray:
@@ -397,8 +420,9 @@ def prepare_state_unprimed(cfg: SolverConfig, starts: jnp.ndarray,
         s = s.replace(task_used=jnp.ones(1, bool))
     else:
         s = init_state(cfg, starts, tasks.shape[0])
-    s = _transitions(cfg, s, tasks)
-    s = _assign(cfg, s, tasks)
+    with jax.named_scope("assign"):
+        s = _transitions(cfg, s, tasks)
+        s = _assign(cfg, s, tasks)
     return s, tasks
 
 
@@ -468,7 +492,9 @@ def run_mapd(cfg: SolverConfig, starts: jnp.ndarray, tasks: jnp.ndarray,
     s, tasks = prepare_state(cfg, starts, tasks, free)
 
     def cond(s):
-        return ~_finished(cfg, s)
+        # the done test reads what the step's bookkeeping wrote
+        with jax.named_scope("record"):
+            return ~_finished(cfg, s)
 
     def body(s):
         return mapd_step(cfg, s, tasks, free)

@@ -362,6 +362,26 @@ def step_stale(cfg: SolverConfig, pos, goal, slot, nh_fn, vpos, vgoal,
     goal-source permutation to commit later (identity where no exchange),
     ``pend_push`` the pushed-goal cell per agent (-1 none).
     """
+    with jax.named_scope("rules"):
+        occ, u, mutual, blocked, has_move, pend_from, pend_push = \
+            _stale_decisions(cfg, pos, goal, slot, nh_fn, vpos, vgoal,
+                             visible, active)
+    # ---- movement: Move decisions execute against physical occupancy ----
+    # Only believed-free moves are attempted (ref Rule 2); every blocked
+    # agent's action is some flavor of wait (WaitForGoalSwap /
+    # WaitForRotation / Wait), so the mover set is simply the unblocked.
+    # (_movement_cascade additionally executes the terminal mutual swap of
+    # committed push pairs — the `mutual` mask.)
+    movers = has_move & ~blocked
+    with jax.named_scope("move"):
+        newpos = _movement_cascade(cfg, pos, u, movers, occ, active, mutual)
+    return newpos, pend_from, pend_push
+
+
+def _stale_decisions(cfg: SolverConfig, pos, goal, slot, nh_fn, vpos, vgoal,
+                     visible, active):
+    """The decision half of :func:`step_stale`: Rule 3/4 on the view.
+    Returns ``(occ, u, mutual, blocked, has_move, pend_from, pend_push)``."""
     n = cfg.num_agents
     idx = jnp.arange(n, dtype=jnp.int32)
     occ = _occupancy(cfg, pos, active)          # physical truth
@@ -462,16 +482,7 @@ def step_stale(cfg: SolverConfig, pos, goal, slot, nh_fn, vpos, vgoal,
     pend_from = pend_from.at[jnp.where(on_cycle, f, n)].set(
         jnp.where(on_cycle, idx, n))
     pend_from = pend_from[:n]
-
-    # ---- movement: Move decisions execute against physical occupancy ----
-    # Only believed-free moves are attempted (ref Rule 2); every blocked
-    # agent's action is some flavor of wait (WaitForGoalSwap /
-    # WaitForRotation / Wait), so the mover set is simply the unblocked.
-    # (_movement_cascade additionally executes the terminal mutual swap of
-    # committed push pairs — the `mutual` mask computed above.)
-    movers = has_move & ~blocked
-    newpos = _movement_cascade(cfg, pos, u, movers, occ, active, mutual)
-    return newpos, pend_from, pend_push
+    return occ, u, mutual, blocked, has_move, pend_from, pend_push
 
 
 def _movement_cascade(cfg: SolverConfig, pos, u, want, occ, active, mutual):
@@ -535,15 +546,17 @@ def step_with_next_hops(cfg: SolverConfig, pos, goal, slot, nh_fn,
     """
     if active is None:
         active = jnp.ones(cfg.num_agents, bool)
-    occ = _occupancy(cfg, pos, active)
+    with jax.named_scope("rules"):
+        occ = _occupancy(cfg, pos, active)
 
-    def round_body(_, gsp):
-        goal, slot, pushed = gsp
-        return _swap_phase_round(cfg, pos, goal, slot, pushed, nh_fn, occ,
-                                 active)
+        def round_body(_, gsp):
+            goal, slot, pushed = gsp
+            return _swap_phase_round(cfg, pos, goal, slot, pushed, nh_fn,
+                                     occ, active)
 
-    goal, slot, _ = jax.lax.fori_loop(
-        0, cfg.swap_rounds, round_body,
-        (goal, slot, jnp.zeros(cfg.num_agents, bool)))
-    pos = _movement_phase(cfg, pos, goal, slot, nh_fn, occ, active)
+        goal, slot, _ = jax.lax.fori_loop(
+            0, cfg.swap_rounds, round_body,
+            (goal, slot, jnp.zeros(cfg.num_agents, bool)))
+    with jax.named_scope("move"):
+        pos = _movement_phase(cfg, pos, goal, slot, nh_fn, occ, active)
     return pos, goal, slot

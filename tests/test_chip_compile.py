@@ -83,7 +83,10 @@ def test_sweep_compiles(topo, side, r, axis):
     d = jax.ShapeDtypeStruct((r, side, side), jnp.int32, sharding=one)
     free = jax.ShapeDtypeStruct((side, side), jnp.bool_, sharding=one)
     fn = jax.jit(lambda d, f: sweep_pallas.sweep(d, f, axis, reverse=False))
-    _check(fn.lower(d, free).compile())
+    compiled = fn.lower(d, free).compile()
+    _check(compiled)
+    # the kernel's name is the custom call's name, which a trace shows
+    assert "%sweep8_rows" in compiled.as_text()
 
 
 def test_flagship_solve_compiles(topo, on_tpu):

@@ -1,0 +1,145 @@
+"""The step's phases are named on the device: every op of the compiled
+step carries the ``jax.named_scope`` path it was traced under in its HLO
+``op_name``, which is how a device trace sums op time per phase
+(``solver.mapd.PHASES``).  Compiled on the CPU at small shapes; scopes are
+metadata only, so what holds here holds for the chip's compiler too."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from p2p_distributed_tswap_tpu.core.config import SolverConfig
+from p2p_distributed_tswap_tpu.solver import mapd
+
+# a warehouse floor like the LoRR one, at a side that is not lane-aligned
+# (the XLA sweep), and the reference fleet's empty square
+WAREHOUSE = dict(height=28, width=100, num_agents=440, replan_chunk=16,
+                 replan_chunk_small=4, max_timesteps=400, record_paths=False)
+EMPTY = dict(height=20, width=20, num_agents=8, replan_chunk=8,
+             max_timesteps=400, record_paths=True)
+TOP = ("assign", "replan", "rules", "move", "record")
+
+
+def _args(cfg, num_tasks):
+    n = cfg.num_agents
+    return (jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((num_tasks, 2), jnp.int32),
+            jax.ShapeDtypeStruct((cfg.height, cfg.width), jnp.bool_))
+
+
+def _step_text(cfg, num_tasks) -> str:
+    args = _args(cfg, num_tasks)
+    state, tasks = jax.eval_shape(
+        lambda *a: mapd.prepare_state(cfg, *a), *args)
+    return jax.jit(mapd.mapd_step, static_argnums=0).lower(
+        cfg, state, tasks, args[2]).compile().as_text()
+
+
+def _solve_text(cfg, num_tasks) -> str:
+    return mapd._run_mapd_jit.lower(cfg, *_args(cfg, num_tasks)).compile() \
+        .as_text()
+
+
+def _computations(text: str) -> dict:
+    """{computation name: [instruction lines]} of an HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.strip().startswith(("%", "ROOT %")):
+            cur.append(line.strip())
+    return comps
+
+
+def _op_name(line: str):
+    m = re.search(r'op_name="([^"]*)"', line)
+    return m.group(1) if m else None
+
+
+def _paths(text: str):
+    """The scope path (components) of every op_name in the module; a fused
+    op joins the op_names it merged with ``;``."""
+    for line in text.splitlines():
+        op = _op_name(line)
+        if op:
+            yield from (p.split("/") for p in op.split(";"))
+
+
+def _loop_ops(comps: dict, body: str):
+    """Instruction lines the loop body runs: its own and those of the
+    loops, branches, calls and fusions inside it (not the reducers that
+    ``to_apply`` names, which run inside their op)."""
+    seen, todo = set(), [body]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:body|condition|calls)=%([\w.\-]+)", line)
+            m = re.search(r"branch_computations=\{([^}]*)\}", line)
+            if m:
+                todo += re.findall(r"%([\w.\-]+)", m.group(1))
+            yield line
+
+
+def _check_phases(text: str, expected) -> None:
+    paths = list(_paths(text))
+    named = {c for p in paths for c in p}
+    assert set(expected) <= named, set(expected) - named
+    for p in paths:
+        for inner in ("sweep", "pack"):
+            if inner in p:
+                assert "replan" in p[:p.index(inner)], p
+
+
+def _check_replan_body(text: str) -> None:
+    comps = _computations(text)
+    bodies = [re.search(r"body=%([\w.\-]+)", line).group(1)
+              for lines in comps.values() for line in lines
+              if " while(" in line
+              and (_op_name(line) or "").endswith("replan/while")]
+    assert len(bodies) == 1
+    checked = 0
+    for line in _loop_ops(comps, bodies[0]):
+        op = _op_name(line)
+        if op is None or re.search(r" (parameter|constant)\(", line):
+            continue   # no metadata: the op runs inside the replan loop
+        for p in op.split(";"):
+            assert "replan" in p.split("/"), line
+        checked += 1
+    assert checked > 0
+
+
+def test_phases_are_the_scope_names():
+    assert len(set(mapd.PHASES)) == len(mapd.PHASES)
+    assert set(TOP) | {"sweep", "pack", "prime"} == set(mapd.PHASES)
+
+
+@pytest.mark.parametrize("conf,stale", [(WAREHOUSE, False), (EMPTY, False),
+                                        (WAREHOUSE, True)],
+                         ids=["warehouse", "empty", "warehouse-stale"])
+def test_step_names_its_phases(conf, stale):
+    # the stale path: radius 15, view refresh 2, commit delay 1 (ROADMAP A3)
+    extra = dict(visibility_radius=15, view_refresh_steps=2,
+                 swap_commit_delay=1) if stale else {}
+    cfg = SolverConfig(**conf, **extra)
+    assert cfg.stale_mode == stale
+    text = _step_text(cfg, 3 * cfg.num_agents)
+    _check_phases(text, TOP + ("sweep", "pack"))
+    _check_replan_body(text)
+
+
+@pytest.mark.parametrize("conf", [WAREHOUSE, EMPTY], ids=["warehouse", "empty"])
+def test_solve_names_its_phases(conf):
+    """The one-program solve runs the t=0 burst (``prime``) and the
+    initial assignment ahead of its step loop; its done test is part of
+    the step's bookkeeping (``record``)."""
+    cfg = SolverConfig(**conf)
+    text = _solve_text(cfg, cfg.num_agents)
+    _check_phases(text, mapd.PHASES)
+    _check_replan_body(text)
+    assert any(p[1:4] == ["while", "cond", "record"] for p in _paths(text))

@@ -300,6 +300,12 @@ def test_packed_plan_wire_live_fleet(built, tiny_map, tmp_path):
                solverd_args=["--cpu"]) as fleet:
         spy = BusClient(port=port, peer_id="wire-spy")
         spy.subscribe("solver")
+        # the manager plans from its first tick, so its opening snapshot
+        # can go out before the spy is subscribed: ask for one on the
+        # plan wire's resync path (what a daemon that lost the chain
+        # does), so the spy sees a chain start
+        spy.publish("solver", {"type": "plan_snapshot_request",
+                               "have_seq": -1})
         time.sleep(4)
         fleet.command("tasks 2")
 
@@ -332,8 +338,10 @@ def test_packed_plan_wire_live_fleet(built, tiny_map, tmp_path):
         assert done, "".join(f.read_text(errors="ignore")[-500:]
                              for f in sorted(log_dir.glob("*.log")))
     assert json_frames == 0, "legacy JSON plan_requests on a packed fleet"
-    assert kinds and kinds[0] == pc.KIND_SNAPSHOT, kinds
-    assert pc.KIND_DELTA in kinds, f"no delta ticks observed: {kinds}"
+    assert pc.KIND_SNAPSHOT in kinds, kinds
+    first = kinds.index(pc.KIND_SNAPSHOT)
+    assert pc.KIND_DELTA in kinds[first + 1:], \
+        f"no delta ticks after the snapshot: {kinds}"
     assert packed_resps >= 1, "no packed plan_responses observed"
 
 

@@ -103,6 +103,54 @@ def test_disabled_mode_is_noop(tmp_path):
     trace.configure(enabled=False)  # fresh registry epoch for later tests
 
 
+def _host_events(logdir) -> set:
+    """Names of the events on the host planes of the newest xplane."""
+    import glob
+    import os
+
+    import jax
+
+    f = sorted(glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                      "*.xplane.pb")))[-1]
+    pd = jax.profiler.ProfileData.from_file(f)
+    return {e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_span_lands_on_the_device_profilers_host_plane(tmp_path):
+    """Under ``jax.profiler`` an enabled span is also a TraceAnnotation:
+    it lands on the xplane's host plane, on the device ops' clock.  A
+    disabled span stays the shared no-op and lands nowhere."""
+    import jax
+    import jax.numpy as jnp
+
+    t = trace.configure(enabled=True, proc="test")
+    try:
+        jax.profiler.start_trace(str(tmp_path / "on"))
+        try:
+            with trace.span("solverd.tick", seq=1):
+                with trace.span("solverd.scatter"):
+                    jnp.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        names = _host_events(tmp_path / "on")
+        assert {"solverd.tick", "solverd.scatter"} <= names
+        # the span's own event is unchanged
+        assert [e["name"] for e in t._drain() if e["ph"] == "X"] == [
+            "solverd.scatter", "solverd.tick"]
+
+        trace.configure(enabled=False)
+        jax.profiler.start_trace(str(tmp_path / "off"))
+        try:
+            with trace.span("solverd.tick"):
+                jnp.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        assert "solverd.tick" not in _host_events(tmp_path / "off")
+    finally:
+        trace.configure(enabled=False)
+
+
 def test_ring_buffer_bounded():
     t = Tracer(proc="ring", enabled=True, capacity=16)
     for i in range(100):
