@@ -41,14 +41,21 @@ from p2p_distributed_tswap_tpu.solver.step import (
 
 _FAR = jnp.int32(1 << 20)  # > any grid manhattan distance
 
+# Agents per bid pass of the assignment: a round compacts the idle agents'
+# ids and scans the backlog for _BID_LANES of them at a time, so its cost
+# follows the number of idle agents, not the fleet.  A fleet of at most
+# _BID_LANES agents bids all its rows at once (no compaction).
+_BID_LANES = 128
+
 # The step's phases as ``jax.named_scope`` names: each op's HLO ``op_name``
 # carries the scopes it was traced under (``jit(mapd_step)/replan/while/
 # body/sweep/...``), so a device trace can sum op time per phase.  ``sweep``
-# and ``pack`` nest inside ``replan``; ``rules`` and ``move`` are opened in
+# and ``pack`` nest inside ``replan``, ``bid`` (one bid pass's own ops,
+# once per pass) inside ``assign``; ``rules`` and ``move`` are opened in
 # solver/step.py; ``record`` is the step's bookkeeping (the path record, the
 # timestep and, in a solve, the done test); ``prime`` is the t=0 burst.
-PHASES = ("assign", "replan", "sweep", "pack", "rules", "move", "record",
-          "prime")
+PHASES = ("assign", "replan", "sweep", "pack", "bid", "rules", "move",
+          "record", "prime")
 
 
 @struct.dataclass
@@ -118,6 +125,20 @@ def _transitions(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray) -> MapdSta
                      need_replan=s.need_replan | tp)
 
 
+def _backlog(cfg: SolverConfig, task_used: jnp.ndarray, tasks: jnp.ndarray):
+    """The pickups' (x, y) and the used flags, padded with used tasks to
+    whole chunks of ``c = min(assign_chunk, T)``; and ``c`` and the chunk
+    count."""
+    w, t = cfg.width, tasks.shape[0]
+    c = min(cfg.assign_chunk, t)
+    nchunks = -(-t // c)
+    pad = nchunks * c - t
+    px = jnp.pad(tasks[:, 0] % w, (0, pad))
+    py = jnp.pad(tasks[:, 0] // w, (0, pad))
+    used = jnp.pad(task_used, (0, pad), constant_values=True)
+    return px, py, used, c, nchunks
+
+
 def _nearest_unused(cfg: SolverConfig, pos: jnp.ndarray,
                     task_used: jnp.ndarray, tasks: jnp.ndarray):
     """Per-agent (distance, index) of the nearest unused task pickup,
@@ -126,13 +147,7 @@ def _nearest_unused(cfg: SolverConfig, pos: jnp.ndarray,
     transient memory is (N, assign_chunk) int32, never the full (N, T)
     matrix (400 MB at the FLAGSHIP rung, 40 GB at EXTREME)."""
     n, w = cfg.num_agents, cfg.width
-    t = tasks.shape[0]
-    c = min(cfg.assign_chunk, t)
-    nchunks = -(-t // c)
-    pad = nchunks * c - t
-    px = jnp.pad(tasks[:, 0] % w, (0, pad))
-    py = jnp.pad(tasks[:, 0] // w, (0, pad))
-    used = jnp.pad(task_used, (0, pad), constant_values=True)
+    px, py, used, c, nchunks = _backlog(cfg, task_used, tasks)
     ax, ay = pos % w, pos // w
 
     def chunk(carry, ci):
@@ -156,6 +171,73 @@ def _nearest_unused(cfg: SolverConfig, pos: jnp.ndarray,
     return bd, bk
 
 
+def _row_min(d: jnp.ndarray):
+    """(minimum, first index of the minimum) of each row of ``d``, out of
+    one reduction over (value, index) pairs."""
+    k = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+
+    def first_min(a, b):
+        (da, ka), (db, kb) = a, b
+        take_b = (db < da) | ((db == da) & (kb < ka))
+        return jnp.where(take_b, db, da), jnp.where(take_b, kb, ka)
+
+    return jax.lax.reduce((d, k), (_FAR, jnp.int32(np.iinfo(np.int32).max)),
+                          first_min, (1,))
+
+
+def _nearest_idle(cfg: SolverConfig, pos: jnp.ndarray, idle: jnp.ndarray,
+                  task_used: jnp.ndarray, tasks: jnp.ndarray):
+    """:func:`_nearest_unused` for the idle agents alone, ``(_FAR, 0)`` for
+    the others.  The idle ids, in ascending order, are bid in
+    ceil(#idle / _BID_LANES) passes of _BID_LANES rows, each scanning the
+    backlog in chunks of ``assign_chunk`` tasks; fill lanes write to a
+    scratch slot.  The chunk is not widened to the (N, assign_chunk)
+    budget: a pass over the whole backlog in one (128, T) fusion compiles
+    to more device code than the full-width bid it replaces."""
+    n, w, lanes = cfg.num_agents, cfg.width, _BID_LANES
+    px, py, used, c, nchunks = _backlog(cfg, task_used, tasks)
+    count = jnp.cumsum(idle, dtype=jnp.int32)   # idle agents up to each id
+    passes = (count[-1] + lanes - 1) // lanes
+    rank = jnp.arange(1, lanes + 1, dtype=jnp.int32)
+
+    def bid(carry):
+        p, bd, bk = carry
+        # the r-th idle agent is the first whose count reaches r; ranks past
+        # the last idle agent give n.  A binary search (a loop, so outside
+        # ``bid``): nonzero's scatter and a sort compile to more device code
+        lane = jnp.searchsorted(count, p * lanes + rank,
+                                method="scan").astype(jnp.int32)
+        with jax.named_scope("bid"):
+            a = pos[jnp.minimum(lane, n - 1)]
+        ax, ay = a % w, a // w
+
+        def chunk(best, o):
+            best_d, best_k = best
+            cpx = jax.lax.dynamic_slice_in_dim(px, o, c)
+            cpy = jax.lax.dynamic_slice_in_dim(py, o, c)
+            cused = jax.lax.dynamic_slice_in_dim(used, o, c)
+            d = (jnp.abs(cpx[None, :] - ax[:, None])
+                 + jnp.abs(cpy[None, :] - ay[:, None]))
+            dk, k = _row_min(jnp.where(cused[None, :], _FAR, d))
+            better = dk < best_d  # strict: ties keep the earlier chunk's
+            return (jnp.where(better, dk, best_d),
+                    jnp.where(better, o + k, best_k)), None
+
+        # the backlog scan runs once per task chunk, so outside ``bid``
+        (dk, k), _ = jax.lax.scan(
+            chunk, (jnp.full(lanes, _FAR, jnp.int32),
+                    jnp.zeros(lanes, jnp.int32)),
+            jnp.arange(0, nchunks * c, c, dtype=jnp.int32))
+        with jax.named_scope("bid"):
+            return p + 1, bd.at[lane].set(dk), bk.at[lane].set(k)
+
+    init = (jnp.int32(0), jnp.full(n + 1, _FAR, jnp.int32),
+            jnp.zeros(n + 1, jnp.int32))
+    _, bd, bk = jax.lax.while_loop(lambda carry: carry[0] < passes, bid,
+                                   init)
+    return bd[:n], bk[:n]
+
+
 def _assign(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray) -> MapdState:
     """Greedy nearest-pickup assignment (ref tswap.rs:123-138), parallelized.
 
@@ -165,6 +247,8 @@ def _assign(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray) -> MapdState:
     tasks go to the lowest proposing agent id; losers re-propose next round
     over the shrunken pool, until no proposal succeeds.  Each round claims
     >=1 task, so rounds <= min(#idle, #unused) — in practice a handful.
+    A fleet wider than _BID_LANES bids only its idle rows
+    (:func:`_nearest_idle`); every bid equals the full-width one's.
 
     Documented approximation (validated for makespan parity like the other
     parallel-ordering divergences, tests/test_solver.py): the result can
@@ -182,7 +266,10 @@ def _assign(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray) -> MapdState:
     def body(carry):
         task_used, goal, phase, agent_task, need, _ = carry
         idle = phase == AgentPhase.IDLE
-        bd, bk = _nearest_unused(cfg, s.pos, task_used, tasks)
+        if n <= _BID_LANES:
+            bd, bk = _nearest_unused(cfg, s.pos, task_used, tasks)
+        else:
+            bd, bk = _nearest_idle(cfg, s.pos, idle, task_used, tasks)
         want = idle & (bd < _FAR)
         # lowest claimant id per task wins (scratch slot t: no OOB scatter)
         winner = jnp.full(t + 1, n, jnp.int32).at[

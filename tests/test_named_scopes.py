@@ -20,6 +20,8 @@ WAREHOUSE = dict(height=28, width=100, num_agents=440, replan_chunk=16,
 EMPTY = dict(height=20, width=20, num_agents=8, replan_chunk=8,
              max_timesteps=400, record_paths=True)
 TOP = ("assign", "replan", "rules", "move", "record")
+# a nested phase and the phase it runs in
+INNER = {"sweep": "replan", "pack": "replan", "bid": "assign"}
 
 
 def _args(cfg, num_tasks):
@@ -91,9 +93,9 @@ def _check_phases(text: str, expected) -> None:
     named = {c for p in paths for c in p}
     assert set(expected) <= named, set(expected) - named
     for p in paths:
-        for inner in ("sweep", "pack"):
+        for inner, outer in INNER.items():
             if inner in p:
-                assert "replan" in p[:p.index(inner)], p
+                assert outer in p[:p.index(inner)], p
 
 
 def _check_replan_body(text: str) -> None:
@@ -116,7 +118,12 @@ def _check_replan_body(text: str) -> None:
 
 def test_phases_are_the_scope_names():
     assert len(set(mapd.PHASES)) == len(mapd.PHASES)
-    assert set(TOP) | {"sweep", "pack", "prime"} == set(mapd.PHASES)
+    assert set(TOP) | set(INNER) | {"prime"} == set(mapd.PHASES)
+
+
+def _bids(cfg) -> tuple:
+    """``bid`` where the fleet is wider than one bid pass."""
+    return ("bid",) if cfg.num_agents > mapd._BID_LANES else ()
 
 
 @pytest.mark.parametrize("conf,stale", [(WAREHOUSE, False), (EMPTY, False),
@@ -129,7 +136,7 @@ def test_step_names_its_phases(conf, stale):
     cfg = SolverConfig(**conf, **extra)
     assert cfg.stale_mode == stale
     text = _step_text(cfg, 3 * cfg.num_agents)
-    _check_phases(text, TOP + ("sweep", "pack"))
+    _check_phases(text, TOP + ("sweep", "pack") + _bids(cfg))
     _check_replan_body(text)
 
 
@@ -140,6 +147,30 @@ def test_solve_names_its_phases(conf):
     the step's bookkeeping (``record``)."""
     cfg = SolverConfig(**conf)
     text = _solve_text(cfg, cfg.num_agents)
-    _check_phases(text, mapd.PHASES)
+    _check_phases(text, set(mapd.PHASES) - {"bid"} | set(_bids(cfg)))
     _check_replan_body(text)
     assert any(p[1:4] == ["while", "cond", "record"] for p in _paths(text))
+
+
+def test_bid_ops_run_once_per_pass():
+    """The ops under ``bid`` sit in the bid-pass loop's own body, never in
+    the task-chunk scan nested in it, so a trace counts their runs as
+    passes (task chunks of one order: 1,320 chunks a pass)."""
+    cfg = SolverConfig(**WAREHOUSE, assign_chunk=1)
+    comps = _computations(_step_text(cfg, 3 * cfg.num_agents))
+
+    def has_bid(lines):
+        return any("bid" in p.split("/") for line in lines
+                   for p in (_op_name(line) or "").split(";"))
+
+    bodies = {c: re.search(r"body=%([\w.\-]+)", line).group(1)
+              for c, lines in comps.items() for line in lines
+              if " while(" in line}
+    passes = [b for b in bodies.values() if has_bid(comps[b])]
+    assert len(passes) == 1
+    scans = [re.search(r"body=%([\w.\-]+)", line).group(1)
+             for line in _loop_ops(comps, passes[0])
+             if " while(" in line]
+    assert scans
+    for scan in scans:
+        assert not has_bid(list(_loop_ops(comps, scan)))
