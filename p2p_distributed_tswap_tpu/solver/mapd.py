@@ -53,9 +53,12 @@ _BID_LANES = 128
 # and ``pack`` nest inside ``replan``, ``bid`` (one bid pass's own ops,
 # once per pass) inside ``assign``; ``rules`` and ``move`` are opened in
 # solver/step.py; ``record`` is the step's bookkeeping (the path record, the
-# timestep and, in a solve, the done test); ``prime`` is the t=0 burst.
+# timestep and, in a solve, the done test); ``prime`` is the t=0 burst.  The
+# stale step nests three more in ``rules``: ``commit`` (the pending goal
+# exchanges landing), ``view`` (the broadcast, the view occupancy and the
+# blocker lookup on it) and ``walk`` (the two Rule-4 ring scans).
 PHASES = ("assign", "replan", "sweep", "pack", "bid", "rules", "move",
-          "record", "prime")
+          "record", "prime", "commit", "view", "walk")
 
 
 @struct.dataclass
@@ -432,7 +435,7 @@ def mapd_step(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray,
     """
     stale = cfg.stale_mode
     if stale:
-        with jax.named_scope("rules"):
+        with jax.named_scope("rules"), jax.named_scope("commit"):
             s = _commit_pending(cfg, s)
     with jax.named_scope("assign"):
         s = _transitions(cfg, s, tasks)
@@ -446,7 +449,8 @@ def mapd_step(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray,
         # step_stale opens "rules" for its decisions and "move" for the
         # cascade itself
         with jax.named_scope("rules"):
-            s = _broadcast_view(cfg, s)
+            with jax.named_scope("view"):
+                s = _broadcast_view(cfg, s)
             if nh_factory is None:
                 nh_fn = lambda sl, po: step_mod.next_hops(cfg, s.dirs, sl, po)
             else:
@@ -459,7 +463,7 @@ def mapd_step(cfg: SolverConfig, s: MapdState, tasks: jnp.ndarray,
             jnp.ones(cfg.num_agents, bool))
         s = s.replace(pos=pos, pend_from=pend_from, pend_push=pend_push)
         if cfg.swap_commit_delay == 0:
-            with jax.named_scope("rules"):
+            with jax.named_scope("rules"), jax.named_scope("commit"):
                 s = _commit_pending(cfg, s)
         with jax.named_scope("record"):
             return _record(cfg, s)

@@ -385,21 +385,23 @@ def _stale_decisions(cfg: SolverConfig, pos, goal, slot, nh_fn, vpos, vgoal,
     n = cfg.num_agents
     idx = jnp.arange(n, dtype=jnp.int32)
     occ = _occupancy(cfg, pos, active)          # physical truth
-    vis = visible & active
-    vocc = _view_occupancy(cfg, vpos, vis)
+    with jax.named_scope("view"):
+        vis = visible & active
+        vocc = _view_occupancy(cfg, vpos, vis)
 
     # own desired next hop: fresh self-knowledge (pos, goal, own field row)
     u = _hops(cfg, nh_fn, slot, pos, goal, active)
     has_move = active & (u != pos)
-    bv = jnp.where(has_move, vocc[u], -1)
-    bv = jnp.where(bv == idx, -1, bv)           # own stale ghost != blocker
-    bvc = jnp.clip(bv, 0, n - 1)
-    # an out-of-radius occupant was evicted from the cache (ref
-    # agent.rs:797): the cell is believed free
-    bv = jnp.where((bv >= 0) & _within_radius_pts(cfg, pos, vpos[bvc]),
-                   bv, -1)
-    bvc = jnp.clip(bv, 0, n - 1)
-    blocked = bv >= 0
+    with jax.named_scope("view"):
+        bv = jnp.where(has_move, vocc[u], -1)
+        bv = jnp.where(bv == idx, -1, bv)       # own stale ghost != blocker
+        bvc = jnp.clip(bv, 0, n - 1)
+        # an out-of-radius occupant was evicted from the cache (ref
+        # agent.rs:797): the cell is believed free
+        bv = jnp.where((bv >= 0) & _within_radius_pts(cfg, pos, vpos[bvc]),
+                       bv, -1)
+        bvc = jnp.clip(bv, 0, n - 1)
+        blocked = bv >= 0
 
     # ---- Rule 3 decision on the view: blocker parked (in view) on its
     # (view) goal -> WaitForGoalSwap ----
@@ -461,22 +463,27 @@ def _stale_decisions(cfg: SolverConfig, pos, goal, slot, nh_fn, vpos, vgoal,
         hit = y == idx
         return (y, oc | hit, ok | (hit & within), within), None
 
-    (_, on_cycle_plain, init_ok, _), _ = jax.lax.scan(
-        member_scan,
-        (f, jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.ones(n, bool)),
-        None, length=cfg.cycle_cap)
-    # all-or-nothing per cycle: members rotate iff SOME member's own walk
-    # succeeded (that member is the initiator broadcasting the rotation)
-    init_ext = jnp.concatenate([init_ok, jnp.array([False])])
+    with jax.named_scope("walk"):
+        (_, on_cycle_plain, init_ok, _), _ = jax.lax.scan(
+            member_scan,
+            (f, jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.ones(n, bool)),
+            None, length=cfg.cycle_cap)
+        # all-or-nothing per cycle: members rotate iff SOME member's own
+        # walk succeeded (that member is the initiator broadcasting the
+        # rotation).  The walk reads f^2(j) onward, so f(j) is read first:
+        # a ring of cycle_cap + 1 members, the longest found, is then read
+        # whole by every member, and rotates as one
+        init_ext = jnp.concatenate([init_ok, jnp.array([False])])
 
-    def prop_scan(carry, _):
-        y, any_ok = carry
-        y = f_ext[y]
-        return (y, any_ok | init_ext[y]), None
+        def prop_scan(carry, _):
+            y, any_ok = carry
+            y = f_ext[y]
+            return (y, any_ok | init_ext[y]), None
 
-    (_, any_ok), _ = jax.lax.scan(
-        prop_scan, (f, init_ok), None, length=cfg.cycle_cap)
-    on_cycle = on_cycle_plain & any_ok
+        (_, any_ok), _ = jax.lax.scan(
+            prop_scan, (f, init_ok | init_ext[f]), None,
+            length=cfg.cycle_cap)
+        on_cycle = on_cycle_plain & any_ok
     # members hand goals backward along the ring, committing with the
     # same latency as swaps (the rotation message arrives next tick)
     pend_from = pend_from.at[jnp.where(on_cycle, f, n)].set(

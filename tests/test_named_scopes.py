@@ -4,6 +4,7 @@ step carries the ``jax.named_scope`` path it was traced under in its HLO
 (``solver.mapd.PHASES``).  Compiled on the CPU at small shapes; scopes are
 metadata only, so what holds here holds for the chip's compiler too."""
 
+import contextlib
 import re
 
 import jax
@@ -21,7 +22,19 @@ EMPTY = dict(height=20, width=20, num_agents=8, replan_chunk=8,
              max_timesteps=400, record_paths=True)
 TOP = ("assign", "replan", "rules", "move", "record")
 # a nested phase and the phase it runs in
-INNER = {"sweep": "replan", "pack": "replan", "bid": "assign"}
+INNER = {"sweep": "replan", "pack": "replan", "bid": "assign",
+         "commit": "rules", "view": "rules", "walk": "rules"}
+# the decentralized stale step (ROADMAP A3), its commit at the next step
+# or at once
+STALE = dict(visibility_radius=15, view_refresh_steps=2, swap_commit_delay=1)
+STALE_NOW = dict(STALE, swap_commit_delay=0)
+# the phases only the stale step has, and the functions each wraps, as the
+# compiled module's stack frames name them
+STALE_PHASES = {
+    "commit": ("_commit_pending",),
+    "view": ("_broadcast_view", "_view_occupancy"),
+    "walk": ("_stale_decisions.<locals>.member_scan",
+             "_stale_decisions.<locals>.prop_scan")}
 
 
 def _args(cfg, num_tasks):
@@ -131,13 +144,16 @@ def _bids(cfg) -> tuple:
                          ids=["warehouse", "empty", "warehouse-stale"])
 def test_step_names_its_phases(conf, stale):
     # the stale path: radius 15, view refresh 2, commit delay 1 (ROADMAP A3)
-    extra = dict(visibility_radius=15, view_refresh_steps=2,
-                 swap_commit_delay=1) if stale else {}
+    extra = STALE if stale else {}
     cfg = SolverConfig(**conf, **extra)
     assert cfg.stale_mode == stale
     text = _step_text(cfg, 3 * cfg.num_agents)
-    _check_phases(text, TOP + ("sweep", "pack") + _bids(cfg))
+    _check_phases(text, TOP + ("sweep", "pack") + _bids(cfg)
+                  + (tuple(STALE_PHASES) if stale else ()))
     _check_replan_body(text)
+    if not stale:
+        named = {c for p in _paths(text) for c in p}
+        assert not named & set(STALE_PHASES)
 
 
 @pytest.mark.parametrize("conf", [WAREHOUSE, EMPTY], ids=["warehouse", "empty"])
@@ -147,7 +163,8 @@ def test_solve_names_its_phases(conf):
     the step's bookkeeping (``record``)."""
     cfg = SolverConfig(**conf)
     text = _solve_text(cfg, cfg.num_agents)
-    _check_phases(text, set(mapd.PHASES) - {"bid"} | set(_bids(cfg)))
+    _check_phases(text, set(mapd.PHASES) - {"bid"} - set(STALE_PHASES)
+                  | set(_bids(cfg)))
     _check_replan_body(text)
     assert any(p[1:4] == ["while", "cond", "record"] for p in _paths(text))
 
@@ -174,3 +191,80 @@ def test_bid_ops_run_once_per_pass():
     assert scans
     for scan in scans:
         assert not has_bid(list(_loop_ops(comps, scan)))
+
+
+def _frame_functions(text: str) -> dict:
+    """{stack frame id: the function of the frame's own location} from
+    the tables a compiled module's text opens with."""
+    tables, section = {}, None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            break
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            section = tables.setdefault(line, {})
+            continue
+        m = re.match(r"(\d+) (.*)$", line)
+        if m and section is not None:
+            section[int(m.group(1))] = m.group(2)
+    fn = {i: v.strip('"') for i, v in tables["FunctionNames"].items()}
+    loc = {i: int(re.search(r"function_name_id=(\d+)", v).group(1))
+           for i, v in tables["FileLocations"].items()}
+    return {i: fn[loc[int(re.search(r"file_location_id=(\d+)", v).group(1))]]
+            for i, v in tables["StackFrames"].items()}
+
+
+@pytest.mark.parametrize("knobs", [STALE, STALE_NOW],
+                         ids=["commit-next-step", "commit-at-once"])
+def test_stale_phases_hold_their_functions(knobs):
+    """Every op the compiled stale step keeps from ``_commit_pending``,
+    ``_broadcast_view``, ``_view_occupancy`` and the two Rule-4 ring scans
+    carries ``rules/commit``, ``rules/view`` or ``rules/walk`` (a fused
+    op in at least one of the paths it joins)."""
+    cfg = SolverConfig(**WAREHOUSE, **knobs)
+    text = _step_text(cfg, 3 * cfg.num_agents)
+    frames = _frame_functions(text)
+    owner = {f: p for p, fs in STALE_PHASES.items() for f in fs}
+    seen = dict.fromkeys(STALE_PHASES, 0)
+    for line in text.splitlines():
+        frame = re.search(r"stack_frame_id=(\d+)", line)
+        op = _op_name(line)
+        # a constant, or a broadcast of one, is shared by the compiler
+        # between the ops that use it and keeps one user's metadata
+        if (not frame or op is None
+                or re.search(r" (constant|parameter)\(|broadcast\(%constant",
+                             line)):
+            continue
+        phase = owner.get(frames[int(frame.group(1))])
+        if phase is None:
+            continue
+        paths = [p.split("/") for p in op.split(";")]
+        assert any(phase in p and "rules" in p[:p.index(phase)]
+                   for p in paths), line
+        seen[phase] += 1
+    assert all(seen.values()), seen
+
+
+def _strip(text: str) -> str:
+    """A compiled module's text without its source tables and metadata:
+    what the device runs."""
+    lines = text.splitlines()
+    body = [lines[0]] + [line for line in lines
+                         if line.startswith(("%", "ENTRY", " ", "}"))]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(body))
+
+
+@pytest.mark.parametrize("knobs", [{}, STALE], ids=["centralized", "stale"])
+def test_scopes_are_metadata_only(knobs, monkeypatch):
+    """The step compiled with its named scopes and with every scope made
+    a no-op differs only in metadata."""
+    cfg = SolverConfig(**WAREHOUSE, **knobs)
+    jax.clear_caches()
+    named = _strip(_step_text(cfg, 3 * cfg.num_agents))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    bare = _step_text(cfg, 3 * cfg.num_agents)
+    jax.clear_caches()
+    assert "rules" not in bare
+    assert named == _strip(bare)
