@@ -96,7 +96,8 @@ class SolverConfig:
     # (src/algorithm/tswap.rs:180-252); extra parallel rounds approximate that.
     swap_rounds: int = 2
     # Upper bound on movement-phase cascade rounds (each round finalizes at
-    # least the front of every convoy; loop exits early at fixpoint).
+    # least the front of every convoy): an agent moves only if its chain of
+    # blockers reaches a free or vacated cell within this many rounds.
     max_move_rounds: int = 64
     # Fast-sweeping rounds cap for distance fields (each round = 4 directional
     # scans; fixpoint is reached much earlier on benchmark maps).

@@ -21,9 +21,10 @@ Step anatomy (one call = one timestep for all N agents):
      to ``f(x)`` — again a pure permutation.
 2. **Movement phase** (ref :257-285): mutual swaps (adjacent pairs that want
    each other's cells) exchange positions; remaining agents cascade into
-   free-or-vacated cells over fixpoint rounds, lowest id winning contested
-   cells.  The cascade preserves vertex-disjointness and never lets two
-   agents cross an edge except via a mutual swap.
+   free-or-vacated cells, lowest id winning contested cells.  The cascade
+   is defined by fixpoint rounds and resolved without them, along blocker
+   chains (:func:`_cascade`).  It preserves vertex-disjointness and never
+   lets two agents cross an edge except via a mutual swap.
 
 Documented divergences from the sequential reference (validated empirically
 for makespan parity in tests):
@@ -243,34 +244,58 @@ def _movement_phase(cfg: SolverConfig, pos, goal, slot, nh_fn, occ, active):
 
     # mutual position swap (ref :269-278): i and blocker want each other's cells
     mutual = has_move & (b >= 0) & (u[bc] == pos) & (b != idx)
-    newpos = jnp.where(mutual, u, pos)
-    decided = ~has_move | mutual
+    return _cascade(cfg, u, b, ~has_move | mutual, jnp.where(mutual, u, pos),
+                    active)
 
-    def cond(state):
-        _, _, changed, r = state
-        return changed & (r < cfg.max_move_rounds)
 
-    def body(state):
-        decided, newpos, _, r = state
-        # final occupancy of decided agents only (padded scratch cell at
-        # index num_cells instead of mode="drop"; see _apply_pair_swaps)
-        occf = jnp.full(cfg.num_cells + 1, -1, jnp.int32).at[
-            jnp.where(decided & active, newpos, cfg.num_cells)].set(idx)
-        # target available: nobody finalized there, and its original occupant
-        # (if any) has finalized a move away
-        orig = b  # original occupant of u (from occ at step start)
-        orig_gone = (orig < 0) | (decided[bc] & (newpos[bc] != u))
-        open_cell = (occf[u] == -1) & orig_gone
-        claimant = ~decided & open_cell
-        win = jnp.full(cfg.num_cells + 1, n, jnp.int32).at[
-            jnp.where(claimant, u, cfg.num_cells)].min(idx)
-        mover = claimant & (win[u] == idx)
-        return (decided | mover, jnp.where(mover, u, newpos),
-                jnp.any(mover), r + 1)
+def _cascade(cfg: SolverConfig, u, b, decided, newpos, active):
+    """Positions after the movement cascade, resolved along blocker chains.
 
-    decided, newpos, _, _ = jax.lax.while_loop(
-        cond, body, (decided, newpos, jnp.bool_(True), jnp.int32(0)))
-    return newpos
+    ``decided`` agents have settled where ``newpos`` says (no move, or a
+    mutual swap); every other agent wants its next cell ``u``, whose
+    occupant at the step's start is ``b`` (-1 if free).  The cascade is
+    the fixpoint of rounds in which an undecided agent enters ``u`` once
+    no settled agent ends there and ``b`` has settled elsewhere, the
+    lowest id first among the claimants of one cell, for at most
+    ``max_move_rounds`` rounds.  Both conditions belong to the cell, so
+    every undecided agent aiming at ``u`` claims it in the same round:
+
+    - one contest, before anything moves, finds the only agent that can
+      ever enter ``u``: its lowest-id undecided claimant, where no
+      settled agent ends on ``u``;
+    - that agent moves in the first round if ``u`` is free or ``b``
+      settled on a move away (a head), and one round after ``b`` moves if
+      ``b`` is undecided (a link); otherwise never.  Its round is its
+      depth along the links, and it moves iff the links reach a head
+      within ``max_move_rounds`` rounds.
+
+    Depths come from pointer doubling: each agent carries the agent its
+    links reach and their count, heads link to a sink, and
+    ceil(log2(max_move_rounds)) steps of (N,) gathers resolve every depth
+    the rounds could reach.  A chain that ends at an agent that cannot
+    move, or runs round a ring, never reaches the sink."""
+    n, nc = cfg.num_agents, cfg.num_cells
+    idx = jnp.arange(n, dtype=jnp.int32)
+    # settled agents claim their final cell with -1, which beats every
+    # undecided claimant's id (padded scratch cell for inactive lanes
+    # instead of mode="drop"; see _apply_pair_swaps)
+    cell = jnp.where(decided, jnp.where(active, newpos, nc), u)
+    claim = jnp.full(nc + 1, n, jnp.int32).at[cell].min(
+        jnp.where(decided, -1, idx))
+    first = ~decided & (claim[u] == idx)
+    # a decided occupant that stays holds u, so the occupant of a first
+    # claimant's cell is gone (a head) unless it is undecided (a link)
+    bc = jnp.clip(b, 0, n - 1)
+    link = first & (b >= 0) & ~decided[bc]
+    # agent n is the sink; agents that never move point at themselves
+    nxt = jnp.append(jnp.where(link, bc, jnp.where(first, n, idx)), n)
+    dist = jnp.append(jnp.ones(n, jnp.int32), 0)
+    for _ in range(max(cfg.max_move_rounds - 1, 0).bit_length()):
+        dist = dist + dist[nxt]
+        nxt = nxt[nxt]
+    # an agent at depth d is dist = d + 1 links from the sink
+    moves = (nxt[:n] == n) & (dist[:n] <= cfg.max_move_rounds)
+    return jnp.where(moves, u, newpos)
 
 
 def step_parallel(cfg: SolverConfig, pos: jnp.ndarray, goal: jnp.ndarray,
@@ -510,33 +535,9 @@ def _movement_cascade(cfg: SolverConfig, pos, u, want, occ, active, mutual):
     (step_stale), which also excludes these pairs from the Rule-4 cycle
     graph — the same face-off must not both swap positions AND pend a
     rotation, or the two resolutions undo each other forever."""
-    n = cfg.num_agents
-    idx = jnp.arange(n, dtype=jnp.int32)
     b = jnp.where(want & ~mutual, occ[u], -1)   # true occupant of target
-    bc = jnp.clip(b, 0, n - 1)
-    decided = mutual | ~want
-    newpos = jnp.where(mutual, u, pos)
-
-    def cond(state):
-        _, _, changed, r = state
-        return changed & (r < cfg.max_move_rounds)
-
-    def body(state):
-        decided, newpos, _, r = state
-        occf = jnp.full(cfg.num_cells + 1, -1, jnp.int32).at[
-            jnp.where(decided & active, newpos, cfg.num_cells)].set(idx)
-        orig_gone = (b < 0) | (decided[bc] & (newpos[bc] != u))
-        open_cell = (occf[u] == -1) & orig_gone
-        claimant = ~decided & open_cell
-        winm = jnp.full(cfg.num_cells + 1, n, jnp.int32).at[
-            jnp.where(claimant, u, cfg.num_cells)].min(idx)
-        mover = claimant & (winm[u] == idx)
-        return (decided | mover, jnp.where(mover, u, newpos),
-                jnp.any(mover), r + 1)
-
-    decided, newpos, _, _ = jax.lax.while_loop(
-        cond, body, (decided, newpos, jnp.bool_(True), jnp.int32(0)))
-    return newpos
+    return _cascade(cfg, u, b, mutual | ~want, jnp.where(mutual, u, pos),
+                    active)
 
 
 def step_with_next_hops(cfg: SolverConfig, pos, goal, slot, nh_fn,
